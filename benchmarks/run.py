@@ -10,21 +10,26 @@ Prints ``name,us_per_call,derived`` CSV rows. Modules:
   architectures        Fig 6 — MAD4PG centralised vs decentralised; MPE
   distribution         Fig 6 bottom right — scaling with num_executors
   roofline             assignment §Roofline table from the dry-run JSON
+
+Every module runs in this process, except that ``roofline`` may start
+dry-run children; it goes first, before this process touches JAX, so no
+child contends with it for a chip.
 """
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import time
 import traceback
 
 MODULES = [
+    "roofline",
     "speedup",
     "switch_game",
     "value_decomposition",
     "architectures",
     "distribution",
-    "roofline",
 ]
 
 
@@ -35,6 +40,14 @@ def main() -> None:
     args = p.parse_args()
 
     mods = [args.only] if args.only else MODULES
+    if "distribution" in mods:
+        # several executors need several devices: split the CPU host
+        # platform into 4 (a TPU host's devices are its chips, untouched).
+        # JAX fixes the count at first use, so this precedes every import.
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=4"
+        ).strip()
     print("name,us_per_call,derived")
     failed = []
     for name in mods:

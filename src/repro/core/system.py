@@ -612,8 +612,6 @@ def make_distributed(
     metrics), so there are no output buffers the state could alias —
     donation would only produce "unusable donation" warnings.
     """
-    from jax.experimental.shard_map import shard_map
-
     n_dev = mesh.shape[axis]
 
     eval_fn = None
@@ -629,10 +627,14 @@ def make_distributed(
 
     tapping = log_every > 0 and log_callback is not None
 
-    def per_device_init(dev_keys):
+    def per_device_init(dev_keys, train_key):
         st = init_system_state(
             system, dev_keys[0], num_envs_per_device, train_env=tenv
         )
+        # envs, buffer and executor keys are the device's own; the trainer
+        # state is the same on every device, so the pmean'd updates keep
+        # the parameters replicated (out_specs P() does not check it)
+        st = st._replace(train=system.init_train(train_key))
         # every leaf gains a leading per-device axis of 1 so the state can
         # cross the shard_map boundary sharded on the data axis (scalars
         # included — P(axis) cannot shard a rank-0 leaf)
@@ -666,28 +668,32 @@ def make_distributed(
             out = out + (jnp.mean(ev.episode_return)[None],)
         return out
 
-    init_fn = jax.jit(
-        shard_map(
-            per_device_init,
-            mesh=mesh,
-            in_specs=(P(axis),),
-            out_specs=P(axis),
-            check_rep=False,
-        )
+    sharded_init = jax.shard_map(
+        per_device_init,
+        mesh=mesh,
+        in_specs=(P(axis), P()),
+        out_specs=P(axis),
+        check_vma=False,
     )
+
+    @jax.jit
+    def init_fn(key):
+        k_train, k_devices = jax.random.split(key)
+        return sharded_init(jax.random.split(k_devices, n_dev), k_train)
+
     out_specs = (P(), P(axis)) if eval_fn is None else (P(), P(axis), P(axis))
     fused = jax.jit(
-        shard_map(
+        jax.shard_map(
             per_device_run,
             mesh=mesh,
             in_specs=(P(axis),),
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
     )
 
     def program(key):
-        return fused(init_fn(jax.random.split(key, n_dev)))
+        return fused(init_fn(key))
 
     program.fused = fused
     program.init_fn = init_fn

@@ -105,7 +105,7 @@ def logical_to_spec(
     """
     if logical_axes is None:
         return P()
-    mesh_shape = dict(zip(mesh.axis_names, mesh.shape.values() if hasattr(mesh.shape, "values") else mesh.shape))
+    mesh_shape = dict(mesh.shape)
     mesh_axes = set(mesh.axis_names)
     used = set()
     entries = []
@@ -176,33 +176,9 @@ def tree_shardings(
     )
 
 
-# One probe for both helpers: the mesh must be installed and read through
-# the same mechanism, or with_logical_constraint silently sees no mesh (e.g.
-# a jax with get_abstract_mesh but no set_mesh would install via the legacy
-# context but read the empty abstract mesh).
-_HAS_AMBIENT_MESH_API = hasattr(jax, "set_mesh") and hasattr(
-    jax.sharding, "get_abstract_mesh"
-)
-
-
 def enter_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-
-    `jax.set_mesh` on newer jax; on older releases Mesh itself is the
-    (legacy thread-resources) context manager.
-    """
-    if _HAS_AMBIENT_MESH_API:
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def _ambient_mesh():
-    """The mesh installed by `enter_mesh`, or None outside any context."""
-    if _HAS_AMBIENT_MESH_API:
-        return jax.sharding.get_abstract_mesh()
-    from jax._src.mesh import thread_resources  # legacy ambient mesh
-
-    return thread_resources.env.physical_mesh
+    """Context manager installing `mesh` as the ambient mesh (`jax.set_mesh`)."""
+    return jax.set_mesh(mesh)
 
 
 def with_logical_constraint(x, logical_axes, rules=None):
@@ -212,8 +188,8 @@ def with_logical_constraint(x, logical_axes, rules=None):
     this is a no-op so the same model code runs in unsharded smoke tests.
     Non-divisible axes are dropped (see logical_to_spec).
     """
-    env_mesh = _ambient_mesh()
-    if env_mesh is None or env_mesh.empty:
+    env_mesh = jax.sharding.get_abstract_mesh()
+    if env_mesh.empty:
         return x
     rules = active_rules() if rules is None else rules
     spec = logical_to_spec(logical_axes, rules, env_mesh, shape=x.shape)

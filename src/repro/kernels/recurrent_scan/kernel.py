@@ -6,11 +6,15 @@ associative under the affine-composition combine
 
     (a1, b1) (+) (a2, b2) = (a2 * a1, a2 * b1 + b2)
 
-so each (time chunk, feature block) tile runs a *log-depth*
-``lax.associative_scan`` over its chunk instead of a sequential loop, then
-splices the chunk onto the running carry with one multiply-add: the
-inclusive prefix ``(A_t, B_t)`` of a chunk maps the incoming hidden state
-straight to ``h_t = A_t * h_in + B_t``.
+so each (time chunk, feature block) tile runs a *log-depth* Hillis-Steele
+scan over its chunk instead of a sequential loop, then splices the chunk
+onto the running carry with one multiply-add: the inclusive prefix
+``(A_t, B_t)`` of a chunk maps the incoming hidden state straight to
+``h_t = A_t * h_in + B_t``.  Each of the ``ceil(log2(chunk))`` steps
+shifts the whole tile down the sublane axis with ``pltpu.roll`` and masks
+the wrapped rows with an iota, so Mosaic never sees a zero-length or
+strided slice of the tile (``lax.associative_scan`` forms those, and
+Mosaic refuses them).
 
 Grid is (feature blocks, seq chunks) with the seq dim innermost/sequential;
 the carry lives in VMEM scratch and persists across chunks (the
@@ -63,8 +67,18 @@ def _scan_kernel(
     # new episode, so the recurrence restarts from b_t alone
     a = a * (1.0 - r_ref[...].astype(jnp.float32))
     # log-depth inclusive prefix of the affine maps within the chunk
-    A, B = jax.lax.associative_scan(_combine, (a, b), axis=0)
-    h = A * h_ref[...] + B          # splice onto the carried-in state
+    # (Hillis-Steele): at step k every row composes with the partial
+    # prefix k rows above it, brought down by a whole-tile sublane roll;
+    # rows with nothing k above them compose with the identity map
+    row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    k = 1
+    while k < chunk:
+        has_prev = row >= k
+        a_prev = jnp.where(has_prev, pltpu.roll(a, k, 0), 1.0)
+        b_prev = jnp.where(has_prev, pltpu.roll(b, k, 0), 0.0)
+        a, b = _combine((a_prev, b_prev), (a, b))
+        k *= 2
+    h = a * h_ref[...] + b          # splice onto the carried-in state
     out_ref[...] = h.astype(out_ref.dtype)
     h_ref[...] = h[chunk - 1 : chunk]
 

@@ -19,11 +19,13 @@ import contextlib
 
 from repro.bench.throughput import run_bench
 from repro.envs import REGISTRY as ENVS
+from repro.launch.compile_cache import use_compilation_cache
 from repro.obs import ConsoleSink, profile_trace
 from repro.systems.registry import REGISTRY as SYSTEMS
 
 
 def main():
+    use_compilation_cache()
     p = argparse.ArgumentParser()
     p.add_argument(
         "--systems", nargs="+", choices=sorted(SYSTEMS) + ["all"],

@@ -21,11 +21,13 @@ import time
 
 from repro.envs import REGISTRY as ENVS
 from repro.eval.sweep import run_sweep
+from repro.launch.compile_cache import use_compilation_cache
 from repro.obs import ConsoleSink
 from repro.systems.registry import REGISTRY as SYSTEMS
 
 
 def main():
+    use_compilation_cache()
     p = argparse.ArgumentParser()
     p.add_argument(
         "--systems", nargs="+", choices=sorted(SYSTEMS) + ["all"],

@@ -17,15 +17,10 @@ MULTI_POD_SHAPE = (2, 16, 16)         # 2 pods x 256 chips
 
 
 def make_auto_mesh(shape, axes):
-    """`jax.make_mesh` with Auto axis types across jax versions.
-
-    Newer jax wants explicit axis_types; on releases without
-    `jax.sharding.AxisType` Auto is already the (only) default.
-    """
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
+    """`jax.make_mesh` with every axis of type Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

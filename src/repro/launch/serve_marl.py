@@ -27,6 +27,7 @@ import json
 import pathlib
 
 from repro.envs import REGISTRY as ENVS
+from repro.launch.compile_cache import use_compilation_cache
 from repro.obs import ConsoleSink, provenance
 from repro.serve import (
     DecisionEngine,
@@ -200,6 +201,7 @@ def to_markdown(results: dict) -> str:
 
 
 def main():
+    use_compilation_cache()
     run(parse_args())
 
 

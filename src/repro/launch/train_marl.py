@@ -48,6 +48,7 @@ from repro.core.system import (
 )
 from repro.distributed.impala import default_unroll_len, train_async
 from repro.envs import REGISTRY as ENVS
+from repro.launch.compile_cache import use_compilation_cache
 from repro.obs import (
     ConsoleSink,
     CsvSink,
@@ -136,8 +137,12 @@ def parse_args(argv=None):
     return p.parse_args(argv)
 
 
-def run(args) -> None:
-    """Launch one training run as configured (the CLI body)."""
+def run(args):
+    """Launch one training run as configured (the CLI body).
+
+    Returns ``(final_train, final_metrics)``: the trained policy state (as
+    ``--save-checkpoint`` persists it) and the summary metrics printed.
+    """
     console = ConsoleSink()
     record = None
     logger = console
@@ -363,9 +368,11 @@ def run(args) -> None:
         path = record.save()
         console.line(f"wrote run record: {path}")
     logger.close()
+    return final_train, final_metrics
 
 
 def main():
+    use_compilation_cache()
     run(parse_args())
 
 

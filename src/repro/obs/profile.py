@@ -4,9 +4,8 @@ Three ways to see *why* a fused run is slow, all attached to the run
 record rather than printed and lost:
 
   * `profile_trace(dir)` — a context manager around ``jax.profiler.trace``
-    writing a TensorBoard/Perfetto trace directory (degrades to a no-op
-    with a recorded reason when the profiler cannot start, so ``--profile``
-    never kills a training run).
+    writing a TensorBoard/Perfetto trace directory (a profiler that cannot
+    start raises: a ``--profile`` run never ends without its trace).
   * `RetraceCounter` — accidental recompiles surface as telemetry, not
     mystery slowness: jax emits `jax.monitoring` duration events per
     jaxpr trace / backend compile, and the counter snapshots them around a
@@ -104,25 +103,14 @@ class RetraceCounter:
 def profile_trace(out_dir):
     """Capture a ``jax.profiler.trace`` into ``out_dir`` around the body.
 
-    Yields a dict describing the capture (``{"trace_dir": ...}``, plus a
-    ``"skipped"`` reason when the profiler could not start); the body runs
-    either way, so profiling can never take down the run it observes.
+    Yields a dict describing the capture (``{"trace_dir": ...}``).  When
+    the profiler cannot start, the error propagates and the body never
+    runs: a profiled run that carried on untraced would look like success.
     """
     out = pathlib.Path(out_dir)
     out.mkdir(parents=True, exist_ok=True)
-    info: Dict[str, Any] = {"trace_dir": str(out)}
-    ctx = None
-    try:
-        ctx = jax.profiler.trace(str(out))
-        ctx.__enter__()
-    except Exception as e:  # profiler backends vary by install
-        ctx = None
-        info["skipped"] = f"{type(e).__name__}: {e}"
-    try:
-        yield info
-    finally:
-        if ctx is not None:
-            ctx.__exit__(None, None, None)
+    with jax.profiler.trace(str(out)):
+        yield {"trace_dir": str(out)}
 
 
 def roofline_summary(hlo_text: str) -> Dict[str, Any]:

@@ -273,6 +273,18 @@ def test_profile_trace_writes_directory(tmp_path):
     assert info["trace_dir"] == str(tmp_path / "trace")
 
 
+def test_profile_trace_raises_when_profiler_cannot_start(tmp_path, monkeypatch):
+    def refuse(*args, **kwargs):
+        raise RuntimeError("no profiler here")
+
+    monkeypatch.setattr(jax.profiler, "trace", refuse)
+    ran = []
+    with pytest.raises(RuntimeError, match="no profiler here"):
+        with profile_trace(tmp_path / "trace"):
+            ran.append(True)
+    assert not ran  # the body never runs untraced
+
+
 def test_roofline_summary_counts_scanned_flops():
     def body(c, _):
         return c @ jnp.ones((8, 8)), None

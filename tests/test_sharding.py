@@ -1,13 +1,11 @@
 """Logical-axis sharding rules (divisibility dropping, profiles) and the
-ambient-mesh fallbacks (`enter_mesh` / `with_logical_constraint` on jax
-releases without the `jax.set_mesh` API)."""
+ambient mesh (`enter_mesh` / `with_logical_constraint` over `jax.set_mesh`)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed import sharding
 from repro.distributed.sharding import (
     DEFAULT_RULES,
     FSDP_TP_RULES,
@@ -23,12 +21,9 @@ from repro.distributed.sharding import (
 
 
 def abstract_mesh(sizes, names):
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.sharding.AbstractMesh(
-            sizes, names, axis_types=(jax.sharding.AxisType.Auto,) * len(names)
-        )
-    # older jax: AbstractMesh takes ((name, size), ...) pairs
-    return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
+    return jax.sharding.AbstractMesh(
+        sizes, names, axis_types=(jax.sharding.AxisType.Auto,) * len(names)
+    )
 
 
 def make_mesh():
@@ -92,10 +87,7 @@ def test_actors_axis_rule_maps_to_data():
     assert logical_to_spec(("actors",), DEFAULT_RULES, mesh) == P("data")
 
 
-# ------------------------------------------------- ambient-mesh fallbacks
-# These run the real construction paths on whatever jax is installed: on
-# releases without jax.set_mesh, enter_mesh falls back to the legacy Mesh
-# context manager and _ambient_mesh reads the legacy thread resources.
+# ----------------------------------------------------------- ambient mesh
 
 
 def device_mesh():
@@ -103,13 +95,12 @@ def device_mesh():
 
 
 def test_enter_mesh_installs_ambient_mesh():
-    assert sharding._ambient_mesh() is None or sharding._ambient_mesh().empty
+    assert jax.sharding.get_abstract_mesh().empty
     with enter_mesh(device_mesh()):
-        ambient = sharding._ambient_mesh()
-        assert ambient is not None and not ambient.empty
+        ambient = jax.sharding.get_abstract_mesh()
+        assert not ambient.empty
         assert tuple(ambient.axis_names) == ("data",)
-    post = sharding._ambient_mesh()
-    assert post is None or post.empty
+    assert jax.sharding.get_abstract_mesh().empty
 
 
 def test_with_logical_constraint_is_noop_outside_mesh():
@@ -127,14 +118,3 @@ def test_with_logical_constraint_applies_inside_mesh():
 
     with enter_mesh(device_mesh()):
         np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x) * 2)
-
-
-def test_legacy_fallback_path_used_when_api_missing(monkeypatch):
-    """Force the legacy branch so it stays covered on every jax release."""
-    monkeypatch.setattr(sharding, "_HAS_AMBIENT_MESH_API", False)
-    mesh = device_mesh()
-    ctx = enter_mesh(mesh)
-    assert ctx is mesh  # legacy: Mesh itself is the context manager
-    with ctx:
-        ambient = sharding._ambient_mesh()
-        assert ambient is not None and not ambient.empty
