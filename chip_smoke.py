@@ -34,7 +34,6 @@ same checkout shows cache hits.
 from __future__ import annotations
 
 import argparse
-import collections
 import importlib.metadata
 import json
 import pathlib
@@ -57,9 +56,6 @@ SERVE_SLOTS = 8
 # docs/KERNELS.md parity table: the Pallas kernel and the gradients
 KERNEL_TOL = 1e-4
 
-_CACHE_EVENTS = collections.Counter()
-
-
 def require_tpu():
     """The first device, or exit non-zero when the backend is not a TPU."""
     device = jax.devices()[0]
@@ -76,11 +72,6 @@ def assert_kernel_compiled(hlo_text: str, what: str) -> None:
         raise AssertionError(f"{what}: no tpu_custom_call in the compiled program")
 
 
-def _count_cache_event(event: str, **kwargs) -> None:
-    if event.startswith("/jax/compilation_cache/"):
-        _CACHE_EVENTS[event.rsplit("/", 1)[-1]] += 1
-
-
 def _finite(tree, what: str) -> None:
     for leaf in jax.tree_util.tree_leaves(tree):
         x = np.asarray(leaf)
@@ -92,17 +83,14 @@ def _phase(name: str, fn) -> None:
     """Run one phase; print what it did, its wall and compile seconds."""
     from repro.obs import RetraceCounter
 
-    hits0 = dict(_CACHE_EVENTS)
     with RetraceCounter() as rc:
         t0 = time.perf_counter()
         info = fn()
         wall = time.perf_counter() - t0
-    hits = {k: v - hits0.get(k, 0) for k, v in _CACHE_EVENTS.items()}
     print(
         f"[{name}] ok  wall_s={wall!r}  compile_s={rc.compile_seconds!r}  "
         f"backend_compiles={rc.backend_compiles}  "
-        f"cache_hits={hits.get('cache_hits', 0)}  "
-        f"cache_misses={hits.get('cache_misses', 0)}  {info}",
+        f"cache_hits={rc.cache_hits}  cache_misses={rc.cache_misses}  {info}",
         flush=True,
     )
 
@@ -300,8 +288,9 @@ def main(argv=None) -> None:
     sys.path.insert(0, str(ROOT / "src"))
     from repro.launch.compile_cache import use_compilation_cache
 
+    from repro.obs.profile import stages
+
     cache_dir = use_compilation_cache()
-    jax.monitoring.register_event_listener(_count_cache_event)
     versions = {}
     for dist in ("jax", "jaxlib", "libtpu"):
         try:
@@ -313,8 +302,9 @@ def main(argv=None) -> None:
 
     t0 = time.perf_counter()
     count = run_four_chips() if args.chips == 4 else run_one_chip()
+    counts = {k: v for k, v in stages().items() if k.startswith("cache_")}
     print(f"all phases passed in {time.perf_counter() - t0!r}s  "
-          f"cache events: {dict(_CACHE_EVENTS)}", flush=True)
+          f"cache events: {counts}", flush=True)
     print(json.dumps({
         "ok": True,
         "device": {

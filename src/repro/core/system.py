@@ -31,6 +31,12 @@ from repro.core.types import EvalMetrics, SystemState, TrainState, Transition
 from repro.envs.api import StepType
 from repro.envs.wrappers import AutoReset, EpisodeStats, replace_reset_keys
 from repro.nn.recurrent import reset_carry
+from repro.obs.profile import EVAL, PHASES, register_program
+
+# Every op of a training iteration runs under one of these scopes
+# (`jax.named_scope`), so the optimized HLO names each op's phase
+# (`repro.obs.profile.op_phases`); they change metadata only.
+_ACT, _ENV_STEP, _OBSERVE, _UPDATE = PHASES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -192,48 +198,50 @@ def _act_phase(system: System, tenv, train, env_state, timestep, carry, key):
     metrics)`` — ``k_upd`` is the update key this step would use if its
     transition completes a batch (the callers own the update gate).
     """
-    key, k_act, k_upd, k_reset = jax.random.split(key, 4)
-    num_envs = jax.tree_util.tree_leaves(env_state)[0].shape[0]
-    env_state = replace_reset_keys(
-        env_state, jax.random.split(k_reset, num_envs)
-    )
+    with jax.named_scope(_ACT):
+        key, k_act, k_upd, k_reset = jax.random.split(key, 4)
+        num_envs = jax.tree_util.tree_leaves(env_state)[0].shape[0]
+        env_state = replace_reset_keys(
+            env_state, jax.random.split(k_reset, num_envs)
+        )
 
-    obs = timestep.observation
-    gs = jax.vmap(tenv.global_state)(env_state)
-    actions, new_carry, extras = system.select_actions(
-        train, obs, gs, carry, k_act, training=True
-    )
-    new_env_state, new_ts = jax.vmap(tenv.step)(env_state, actions)
-    tr = Transition(
-        obs=obs,
-        actions=actions,
-        rewards=new_ts.reward,
-        discount=new_ts.discount,
-        next_obs=new_ts.observation,
-        state=gs,
-        next_state=jax.vmap(tenv.global_state)(new_env_state),
-        extras=extras,
-        step_type=timestep.step_type,
-    )
+        obs = timestep.observation
+        gs = jax.vmap(tenv.global_state)(env_state)
+        actions, new_carry, extras = system.select_actions(
+            train, obs, gs, carry, k_act, training=True
+        )
+    with jax.named_scope(_ENV_STEP):
+        new_env_state, new_ts = jax.vmap(tenv.step)(env_state, actions)
+        tr = Transition(
+            obs=obs,
+            actions=actions,
+            rewards=new_ts.reward,
+            discount=new_ts.discount,
+            next_obs=new_ts.observation,
+            state=gs,
+            next_state=jax.vmap(tenv.global_state)(new_env_state),
+            extras=extras,
+            step_type=timestep.step_type,
+        )
 
-    # a FIRST out of step marks an auto-reset boundary: executor carries
-    # (recurrent cores, comm messages) restart with the new episode
-    done = new_ts.step_type == StepType.FIRST
-    new_carry = reset_carry(
-        new_carry, done, initial=system.initial_carry((num_envs,))
-    )
+        # a FIRST out of step marks an auto-reset boundary: executor carries
+        # (recurrent cores, comm messages) restart with the new episode
+        done = new_ts.step_type == StepType.FIRST
+        new_carry = reset_carry(
+            new_carry, done, initial=system.initial_carry((num_envs,))
+        )
 
-    ep_reward = jnp.mean(jnp.stack(list(new_ts.reward.values())))
-    done_f = done.astype(jnp.float32)
-    # mean return of the episodes that completed this iteration (0 if none)
-    ep_return = jnp.sum(
-        _team_return(new_env_state.last_returns) * done_f
-    ) / jnp.maximum(jnp.sum(done_f), 1.0)
-    metrics = {
-        "reward": ep_reward,
-        "done_frac": jnp.mean(done_f),
-        "episode_return": ep_return,
-    }
+        ep_reward = jnp.mean(jnp.stack(list(new_ts.reward.values())))
+        done_f = done.astype(jnp.float32)
+        # mean return of the episodes that completed this iteration (0 if none)
+        ep_return = jnp.sum(
+            _team_return(new_env_state.last_returns) * done_f
+        ) / jnp.maximum(jnp.sum(done_f), 1.0)
+        metrics = {
+            "reward": ep_reward,
+            "done_frac": jnp.mean(done_f),
+            "episode_return": ep_return,
+        }
     return new_env_state, new_ts, new_carry, key, tr, k_upd, metrics
 
 
@@ -257,9 +265,15 @@ def _step_phase(system: System, tenv, st: SystemState, key):
     env_state, ts, carry, key, tr, k_upd, metrics = _act_phase(
         system, tenv, st.train, st.env_state, st.timestep, st.carry, key
     )
-    buffer = system.observe(st.buffer, tr)
+    buffer = _observe(system, st.buffer, tr)
     st = SystemState(st.train, buffer, env_state, ts, carry, key)
     return st, k_upd, metrics
+
+
+def _observe(system: System, buffer, tr):
+    """The dataset write of one iteration's transition batch."""
+    with jax.named_scope(_OBSERVE):
+        return system.observe(buffer, tr)
 
 
 def _do_updates(system: System, train, buffer, k_upd):
@@ -271,19 +285,22 @@ def _do_updates(system: System, train, buffer, k_upd):
     return train, buffer
 
 
-def _one_iteration(system: System, tenv, carry, key):
-    """One vectorised step of every env + gated updates. carry = SystemState.
+def _gated_update(system: System, train, buffer, k_upd):
+    """The trainer update(s), gated on buffer readiness (replay fill, or a
+    complete rollout — in which case update consumes and resets it)."""
+    with jax.named_scope(_UPDATE):
+        return jax.lax.cond(
+            system.can_sample(buffer),
+            lambda tb: _do_updates(system, tb[0], tb[1], k_upd),
+            lambda tb: tb,
+            (train, buffer),
+        )
 
-    The trainer update(s) are gated on buffer readiness (replay fill, or a
-    complete rollout — in which case update consumes and resets it).
-    """
+
+def _one_iteration(system: System, tenv, carry, key):
+    """One vectorised step of every env + gated updates. carry = SystemState."""
     st, k_upd, metrics = _step_phase(system, tenv, carry, key)
-    train, buffer = jax.lax.cond(
-        system.can_sample(st.buffer),
-        lambda tb: _do_updates(system, tb[0], tb[1], k_upd),
-        lambda tb: tb,
-        (st.train, st.buffer),
-    )
+    train, buffer = _gated_update(system, st.train, st.buffer, k_upd)
     return st._replace(train=train, buffer=buffer), metrics
 
 
@@ -305,15 +322,16 @@ def _one_iteration_seeds(system: System, tenv, carry, keys):
     st, k_upd, metrics = jax.vmap(
         functools.partial(_step_phase, system, tenv)
     )(carry, keys)
-    ready = jax.vmap(system.can_sample)(st.buffer)
-    train, buffer = jax.lax.cond(
-        jnp.all(ready),
-        lambda tb: jax.vmap(
-            functools.partial(_do_updates, system)
-        )(tb[0], tb[1], k_upd),
-        lambda tb: tb,
-        (st.train, st.buffer),
-    )
+    with jax.named_scope(_UPDATE):
+        ready = jax.vmap(system.can_sample)(st.buffer)
+        train, buffer = jax.lax.cond(
+            jnp.all(ready),
+            lambda tb: jax.vmap(
+                functools.partial(_do_updates, system)
+            )(tb[0], tb[1], k_upd),
+            lambda tb: tb,
+            (st.train, st.buffer),
+        )
     return st._replace(train=train, buffer=buffer), metrics
 
 
@@ -463,13 +481,14 @@ def make_anakin(
                 # untapped block scan untouched
                 xs = b * eval_every + jnp.arange(eval_every) if tapping else None
                 st, metrics = jax.lax.scan(train_body, st, xs, length=eval_every)
-                if num_seeds is None:
-                    k_eval, k_next = jax.random.split(st.key)
-                    ev = eval_fn(st.train, k_eval)
-                else:
-                    split = jax.vmap(jax.random.split)(st.key)
-                    k_eval, k_next = split[:, 0], split[:, 1]
-                    ev = jax.vmap(eval_fn)(st.train, k_eval)
+                with jax.named_scope(EVAL):
+                    if num_seeds is None:
+                        k_eval, k_next = jax.random.split(st.key)
+                        ev = eval_fn(st.train, k_eval)
+                    else:
+                        split = jax.vmap(jax.random.split)(st.key)
+                        k_eval, k_next = split[:, 0], split[:, 1]
+                        ev = jax.vmap(eval_fn)(st.train, k_eval)
                 return st._replace(key=k_next), (metrics, ev)
 
             bxs = jnp.arange(num_blocks) if tapping else None
@@ -493,6 +512,7 @@ def make_anakin(
         )
     )
     fused = jax.jit(run, donate_argnums=0)
+    _register(fused, init_fn)
 
     def program(key):
         return fused(init_fn(key))
@@ -501,6 +521,14 @@ def make_anakin(
     program.fused = fused
     program.init_fn = init_fn
     return program
+
+
+def _register(fused, init_fn):
+    """Register ``fused`` for the op -> phase map, its argument being what
+    ``init_fn`` builds from a typed PRNG key (`jax.random.key`)."""
+    register_program(
+        fused, lambda: (jax.eval_shape(init_fn, jax.random.key(0)),)
+    )
 
 
 def _unalias(tree):
@@ -663,8 +691,9 @@ def make_distributed(
             lambda x: jnp.mean(x)[None], metrics
         )
         if eval_fn is not None:
-            k_eval, _ = jax.random.split(st.key)
-            ev = eval_fn(st.train, k_eval)
+            with jax.named_scope(EVAL):
+                k_eval, _ = jax.random.split(st.key)
+                ev = eval_fn(st.train, k_eval)
             out = out + (jnp.mean(ev.episode_return)[None],)
         return out
 
@@ -691,6 +720,7 @@ def make_distributed(
             check_vma=False,
         )
     )
+    _register(fused, init_fn)
 
     def program(key):
         return fused(init_fn(key))

@@ -54,7 +54,8 @@ from repro.core.buffer import (
 from repro.core.system import (
     System,
     _act_phase,
-    _do_updates,
+    _gated_update,
+    _observe,
     _tap_body,
     _training_env,
     _unalias,
@@ -268,14 +269,9 @@ def make_async(
         def _row(carry, x):
             train, buffer = carry
             tr, k_data = x
-            buffer = system.observe(buffer, tr)
-            train, buffer = jax.lax.cond(
-                system.can_sample(buffer),
-                lambda tb: _do_updates(
-                    system, tb[0], tb[1], jax.random.wrap_key_data(k_data)
-                ),
-                lambda tb: tb,
-                (train, buffer),
+            buffer = _observe(system, buffer, tr)
+            train, buffer = _gated_update(
+                system, train, buffer, jax.random.wrap_key_data(k_data)
             )
             return (train, buffer), ()
 

@@ -58,7 +58,6 @@ from repro.obs import (
     RetraceCounter,
     RunRecord,
     SeedAggregator,
-    measure_phase_timing,
     profile_trace,
     roofline_summary,
 )
@@ -345,15 +344,6 @@ def run(args):
             total_seconds=wall,
             compile_seconds=retrace["compile_seconds"],
             steady_seconds=max(wall - retrace["compile_seconds"], 0.0),
-        )
-        record.update(
-            "timing",
-            phases=measure_phase_timing(
-                system, args.num_envs, jax.random.key(args.seed),
-                eval_episodes=(
-                    args.eval_episodes if args.eval_every > 0 else 0
-                ),
-            ),
         )
         record.update("metrics", **final_metrics)
         if args.profile:

@@ -3,10 +3,10 @@
 The observability subsystem: what a run is doing *while it runs* (the
 in-jit `MetricTap` + logger sinks), what it did once it finished (the
 structured `RunRecord` under ``results/runs/<run_id>/``), and why it was
-slow (`profile_trace` / `RetraceCounter` / `roofline_summary`).  See
+slow (`profile_trace` / `RetraceCounter` / `roofline_summary`, and the
+runners' named phases).  See
 ``docs/OBSERVABILITY.md`` for the run-record schema and workflows.
 """
-from repro.obs.phases import measure_phase_timing
 from repro.obs.profile import (
     RetraceCounter,
     profile_trace,
@@ -36,7 +36,6 @@ __all__ = [
     "SeedAggregator",
     "default_run_id",
     "git_sha",
-    "measure_phase_timing",
     "profile_trace",
     "provenance",
     "roofline_summary",

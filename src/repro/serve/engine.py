@@ -100,7 +100,11 @@ class DecisionEngine:
     live slots' joint actions, and retires episodes that hit LAST (the
     slot is freed for the next admission, its carry already zeroed by the
     in-tick boundary reset).  Per-tick wall time and live-slot counts are
-    appended to ``tick_log`` for the BENCH_serve latency/throughput stats.
+    appended to ``tick_log`` for the BENCH_serve latency/throughput stats,
+    with the tick split into ``admit_s``, ``decide_s`` (the decision
+    program's dispatch and its device-to-host copies) and ``bookkeep_s``
+    (the live-slot loop); the same three parts are ``serve.admit``,
+    ``serve.decide`` and ``serve.bookkeep`` spans on a profiler trace.
     """
 
     def __init__(
@@ -132,7 +136,7 @@ class DecisionEngine:
         self.queue: Deque[ServeRequest] = deque()
         self.slots: List[Optional[ServeRequest]] = [None] * max_slots
         self.finished: List[ServeRequest] = []
-        self.tick_log: List[Dict[str, float]] = []  # wall seconds + live count
+        self.tick_log: List[Dict[str, float]] = []  # per-tick wall split + live count
 
         # the pool: batched env state / timestep / carry, one row per slot
         # (free rows hold placeholder episodes that are stepped and ignored)
@@ -245,49 +249,59 @@ class DecisionEngine:
         this tick — the decisions a server would ship back to its users.
         """
         t0 = time.perf_counter()
-        self._admit()
+        with jax.profiler.TraceAnnotation("serve.admit"):
+            self._admit()
+        t_admit = time.perf_counter()
         live = [i for i, r in enumerate(self.slots) if r is not None]
         if not live:
             return {}
-        k_act = jax.random.fold_in(self._act_base, self._t)
-        self._t += 1
-        actions, self._env_state, self._ts, self._carry, rewards, ended = (
-            self._tick_jit(
-                self.train, self._env_state, self._ts, self._carry, k_act
+        with jax.profiler.TraceAnnotation("serve.decide"):
+            k_act = jax.random.fold_in(self._act_base, self._t)
+            self._t += 1
+            actions, self._env_state, self._ts, self._carry, rewards, ended = (
+                self._tick_jit(
+                    self.train, self._env_state, self._ts, self._carry, k_act
+                )
             )
-        )
-        actions = {a: np.asarray(v) for a, v in actions.items()}
-        rewards = {a: np.asarray(v, np.float32) for a, v in rewards.items()}
-        ended = np.asarray(ended)
+            actions = {a: np.asarray(v) for a, v in actions.items()}
+            rewards = {a: np.asarray(v, np.float32) for a, v in rewards.items()}
+            ended = np.asarray(ended)
+        t_decide = time.perf_counter()
 
         emitted: Dict[int, Dict[str, int]] = {}
-        for i in live:
-            req = self.slots[i]
-            decision = {a: actions[a][i] for a in self._ids}
-            emitted[req.uid] = decision
-            if self.record_actions:
-                req.actions.append(decision)
-            for a in self._ids:
-                # float32 accumulation, same order as the evaluator's scan
-                req.agent_returns[a] = np.float32(
-                    req.agent_returns[a] + rewards[a][i]
-                )
-            req.length += 1
-            if ended[i]:
-                req.episode_return = float(
-                    np.mean(
-                        np.stack(
-                            [req.agent_returns[a] for a in self._ids]
-                        ).astype(np.float32)
+        with jax.profiler.TraceAnnotation("serve.bookkeep"):
+            for i in live:
+                req = self.slots[i]
+                decision = {a: actions[a][i] for a in self._ids}
+                emitted[req.uid] = decision
+                if self.record_actions:
+                    req.actions.append(decision)
+                for a in self._ids:
+                    # float32 accumulation, same order as the evaluator's scan
+                    req.agent_returns[a] = np.float32(
+                        req.agent_returns[a] + rewards[a][i]
                     )
-                )
-                req.done = True
-                self.finished.append(req)
-                self.slots[i] = None
-                self._live[i] = False
-        self.tick_log.append(
-            {"seconds": time.perf_counter() - t0, "live": len(live)}
-        )
+                req.length += 1
+                if ended[i]:
+                    req.episode_return = float(
+                        np.mean(
+                            np.stack(
+                                [req.agent_returns[a] for a in self._ids]
+                            ).astype(np.float32)
+                        )
+                    )
+                    req.done = True
+                    self.finished.append(req)
+                    self.slots[i] = None
+                    self._live[i] = False
+        t_end = time.perf_counter()
+        self.tick_log.append({
+            "seconds": t_end - t0,
+            "live": len(live),
+            "admit_s": t_admit - t0,
+            "decide_s": t_decide - t_admit,
+            "bookkeep_s": t_end - t_decide,
+        })
         return emitted
 
     def run_until_drained(self, max_ticks: int = 100_000) -> List[ServeRequest]:

@@ -57,7 +57,11 @@ from repro.core.types import Carry, TrainState, Transition
 from repro.envs.api import EnvSpec, StepType
 from repro.nn import MLP
 from repro.nn.recurrent import make_core, window_start_carry
+from repro.obs.profile import UPDATE_PARTS
 from repro.systems.vtrace import vtrace_advantages
+
+# Scopes of the update's parts on the device timeline (metadata only).
+_ADVANTAGE, _MINIBATCH, _GRAD, _OPTIMIZER = UPDATE_PARTS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -274,43 +278,48 @@ def make_ppo_system(env, cfg: PPOConfig, centralised: bool, name: str) -> System
         metrics["loss"] = total
         return total, metrics
 
-    def update(train: TrainState, buffer, key):
-        """Consume the rollout: GAE or V-trace, then epochs of minibatches."""
-        traj: Transition = rollout_take(buffer)  # leaves (T, B, ...)
-        # Bootstrap from the final next-observation with the learner's
-        # current params.  Under the synchronous runners these equal the
-        # behaviour params (no update fired mid-rollout), so GAE sees
-        # behaviour values exactly as if recorded at act time; under the
-        # async runner with staleness they differ, and the V-trace branch
-        # re-evaluates the whole trajectory under current params and
-        # importance-corrects against the stored behaviour log-probs.
+    def advantages(train: TrainState, traj: Transition):
+        """Per-agent advantages and returns of a stored trajectory.
+
+        Bootstrap from the final next-observation with the learner's
+        current params.  Under the synchronous runners these equal the
+        behaviour params (no update fired mid-rollout), so GAE sees
+        behaviour values exactly as if recorded at act time; under the
+        async runner with staleness they differ, and the V-trace branch
+        re-evaluates the whole trajectory under current params and
+        importance-corrects against the stored behaviour log-probs.
+        """
         last_obs = jax.tree_util.tree_map(lambda x: x[-1], traj.next_obs)
         last_state = traj.next_state[-1]
         last_values = {
             a: value_fn(train.params, a, critic_obs(last_obs, last_state, a))
             for a in ids
         }
-        if cfg.use_vtrace:
-            adv, ret = {}, {}
-            disc = traj.discount * cfg.gamma
-            for a in ids:
-                lp_all = jax.nn.log_softmax(
-                    logits_fn(train.params, a, traj.obs[a])
-                )
-                curr_lp = jnp.take_along_axis(
-                    lp_all, traj.actions[a][..., None], axis=-1
-                )[..., 0]
-                curr_v = value_fn(
-                    train.params, a, critic_obs(traj.obs, traj.state, a)
-                )
-                adv[a], ret[a] = vtrace_advantages(
-                    curr_lp, traj.extras["logp"][a], curr_v, last_values[a],
-                    traj.rewards[a], disc,
-                    clip_rho=cfg.vtrace_clip_rho, clip_c=cfg.vtrace_clip_c,
-                    lam=cfg.gae_lambda,
-                )
-        else:
-            adv, ret = gae(traj, last_values)
+        if not cfg.use_vtrace:
+            return gae(traj, last_values)
+        adv, ret = {}, {}
+        disc = traj.discount * cfg.gamma
+        for a in ids:
+            lp_all = jax.nn.log_softmax(logits_fn(train.params, a, traj.obs[a]))
+            curr_lp = jnp.take_along_axis(
+                lp_all, traj.actions[a][..., None], axis=-1
+            )[..., 0]
+            curr_v = value_fn(
+                train.params, a, critic_obs(traj.obs, traj.state, a)
+            )
+            adv[a], ret[a] = vtrace_advantages(
+                curr_lp, traj.extras["logp"][a], curr_v, last_values[a],
+                traj.rewards[a], disc,
+                clip_rho=cfg.vtrace_clip_rho, clip_c=cfg.vtrace_clip_c,
+                lam=cfg.gae_lambda,
+            )
+        return adv, ret
+
+    def update(train: TrainState, buffer, key):
+        """Consume the rollout: GAE or V-trace, then epochs of minibatches."""
+        traj: Transition = rollout_take(buffer)  # leaves (T, B, ...)
+        with jax.named_scope(_ADVANTAGE):
+            adv, ret = advantages(train, traj)
         T, B = traj.discount.shape
         data = dict(
             obs=traj.obs,
@@ -327,27 +336,30 @@ def make_ppo_system(env, cfg: PPOConfig, centralised: bool, name: str) -> System
         def epoch(carry, _):
             """One PPO epoch: shuffle, split into minibatches, scan `mb_step`."""
             params, opt_state, key = carry
-            key, kp = jax.random.split(key)
-            perm = jax.random.permutation(kp, T * B)
-            shuffled = jax.tree_util.tree_map(lambda x: x[perm], flat)
-            mb_size = (T * B) // cfg.num_minibatches
-            mbs = jax.tree_util.tree_map(
-                lambda x: x[: mb_size * cfg.num_minibatches].reshape(
-                    (cfg.num_minibatches, mb_size) + x.shape[1:]
-                ),
-                shuffled,
-            )
+            with jax.named_scope(_MINIBATCH):
+                key, kp = jax.random.split(key)
+                perm = jax.random.permutation(kp, T * B)
+                shuffled = jax.tree_util.tree_map(lambda x: x[perm], flat)
+                mb_size = (T * B) // cfg.num_minibatches
+                mbs = jax.tree_util.tree_map(
+                    lambda x: x[: mb_size * cfg.num_minibatches].reshape(
+                        (cfg.num_minibatches, mb_size) + x.shape[1:]
+                    ),
+                    shuffled,
+                )
 
             def mb_step(carry, mb):
                 """One minibatch gradient step (optionally pmean over the mesh)."""
                 params, opt_state = carry
-                (loss, m), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                    params, mb
-                )
-                if cfg.distributed_axis:
-                    grads = jax.lax.pmean(grads, cfg.distributed_axis)
-                updates, opt_state = opt.update(grads, opt_state, params)
-                params = optim.apply_updates(params, updates)
+                with jax.named_scope(_GRAD):
+                    (loss, m), grads = jax.value_and_grad(
+                        loss_fn, has_aux=True
+                    )(params, mb)
+                    if cfg.distributed_axis:
+                        grads = jax.lax.pmean(grads, cfg.distributed_axis)
+                with jax.named_scope(_OPTIMIZER):
+                    updates, opt_state = opt.update(grads, opt_state, params)
+                    params = optim.apply_updates(params, updates)
                 return (params, opt_state), loss
 
             (params, opt_state), losses = jax.lax.scan(
@@ -575,49 +587,52 @@ def make_recurrent_ppo_system(env, cfg: PPOConfig, centralised: bool, name: str)
         """Consume the rollout: GAE, then epochs of sequence minibatches."""
         traj: Transition = rollout_take(buffer)  # leaves (T, B, ...)
         T, B = traj.discount.shape
-        resets = traj.step_type == StepType.FIRST  # (T, B)
-        carry0 = window_start_carry(traj.extras, initial_carry, (B,))
+        with jax.named_scope(_ADVANTAGE):
+            resets = traj.step_type == StepType.FIRST  # (T, B)
+            carry0 = window_start_carry(traj.extras, initial_carry, (B,))
 
-        # Bootstrap value at T: replay the critic cores over the window from
-        # the stored start carry (same params as act time — on-policy), then
-        # one step on the final next-observation.  When the last row ended
-        # an episode its discount is 0, so the (stale-memory) bootstrap for
-        # the just-started episode is gated out of GAE entirely.
-        last_obs = jax.tree_util.tree_map(lambda x: x[-1], traj.next_obs)
-        last_state = traj.next_state[-1]
-        last_values, curr_values = {}, {}
-        for a in ids:
-            h_t, v_seq = critic.unroll(
-                train.params, a, carry0.hidden["critic"][a],
-                critic_obs(traj.obs, traj.state, a), resets,
-            )
-            _, v = critic.step(
-                train.params, a, h_t, critic_obs(last_obs, last_state, a)
-            )
-            last_values[a] = v[..., 0]
-            curr_values[a] = v_seq[..., 0]
-        if cfg.use_vtrace:
-            # off-policy correction for stale-snapshot actors: current
-            # log-probs from an actor BPTT re-run over the stored window,
-            # current values from the critic unroll above
-            adv, ret = {}, {}
-            disc = traj.discount * cfg.gamma
+            # Bootstrap value at T: replay the critic cores over the window
+            # from the stored start carry (same params as act time —
+            # on-policy), then one step on the final next-observation.  When
+            # the last row ended an episode its discount is 0, so the
+            # (stale-memory) bootstrap for the just-started episode is gated
+            # out of GAE entirely.
+            last_obs = jax.tree_util.tree_map(lambda x: x[-1], traj.next_obs)
+            last_state = traj.next_state[-1]
+            last_values, curr_values = {}, {}
             for a in ids:
-                _, lg = actor.unroll(
-                    train.params, a, carry0.hidden["actor"][a],
-                    traj.obs[a], resets,
+                h_t, v_seq = critic.unroll(
+                    train.params, a, carry0.hidden["critic"][a],
+                    critic_obs(traj.obs, traj.state, a), resets,
                 )
-                curr_lp = jnp.take_along_axis(
-                    jax.nn.log_softmax(lg), traj.actions[a][..., None], axis=-1
-                )[..., 0]
-                adv[a], ret[a] = vtrace_advantages(
-                    curr_lp, traj.extras["logp"][a], curr_values[a],
-                    last_values[a], traj.rewards[a], disc,
-                    clip_rho=cfg.vtrace_clip_rho, clip_c=cfg.vtrace_clip_c,
-                    lam=cfg.gae_lambda,
+                _, v = critic.step(
+                    train.params, a, h_t, critic_obs(last_obs, last_state, a)
                 )
-        else:
-            adv, ret = gae(traj, last_values)
+                last_values[a] = v[..., 0]
+                curr_values[a] = v_seq[..., 0]
+            if cfg.use_vtrace:
+                # off-policy correction for stale-snapshot actors: current
+                # log-probs from an actor BPTT re-run over the stored window,
+                # current values from the critic unroll above
+                adv, ret = {}, {}
+                disc = traj.discount * cfg.gamma
+                for a in ids:
+                    _, lg = actor.unroll(
+                        train.params, a, carry0.hidden["actor"][a],
+                        traj.obs[a], resets,
+                    )
+                    curr_lp = jnp.take_along_axis(
+                        jax.nn.log_softmax(lg), traj.actions[a][..., None],
+                        axis=-1,
+                    )[..., 0]
+                    adv[a], ret[a] = vtrace_advantages(
+                        curr_lp, traj.extras["logp"][a], curr_values[a],
+                        last_values[a], traj.rewards[a], disc,
+                        clip_rho=cfg.vtrace_clip_rho, clip_c=cfg.vtrace_clip_c,
+                        lam=cfg.gae_lambda,
+                    )
+            else:
+                adv, ret = gae(traj, last_values)
 
         data = dict(
             obs=traj.obs,
@@ -640,30 +655,36 @@ def make_recurrent_ppo_system(env, cfg: PPOConfig, centralised: bool, name: str)
         def epoch(carry, _):
             """One PPO epoch: shuffle, split into minibatches, scan `mb_step`."""
             params, opt_state, key = carry
-            key, kp = jax.random.split(key)
-            perm = jax.random.permutation(kp, B)[: n_mb * mb_size]
-            # (T, B, ...) -> (n_mb, T, mb_size, ...)
-            mbs = jax.tree_util.tree_map(
-                lambda x: jnp.moveaxis(
-                    x[:, perm].reshape((T, n_mb, mb_size) + x.shape[2:]), 1, 0
-                ),
-                data,
-            )
-            # window-start carries ride the same env shuffle: (n_mb, mb_size, H)
-            mbs["carry0"] = jax.tree_util.tree_map(
-                lambda x: x[perm].reshape((n_mb, mb_size) + x.shape[1:]), carry0
-            )
+            with jax.named_scope(_MINIBATCH):
+                key, kp = jax.random.split(key)
+                perm = jax.random.permutation(kp, B)[: n_mb * mb_size]
+                # (T, B, ...) -> (n_mb, T, mb_size, ...)
+                mbs = jax.tree_util.tree_map(
+                    lambda x: jnp.moveaxis(
+                        x[:, perm].reshape((T, n_mb, mb_size) + x.shape[2:]),
+                        1, 0,
+                    ),
+                    data,
+                )
+                # window-start carries ride the same env shuffle:
+                # (n_mb, mb_size, H)
+                mbs["carry0"] = jax.tree_util.tree_map(
+                    lambda x: x[perm].reshape((n_mb, mb_size) + x.shape[1:]),
+                    carry0,
+                )
 
             def mb_step(carry, mb):
                 """One minibatch gradient step (optionally pmean over the mesh)."""
                 params, opt_state = carry
-                (loss, m), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                    params, mb
-                )
-                if cfg.distributed_axis:
-                    grads = jax.lax.pmean(grads, cfg.distributed_axis)
-                updates, opt_state = opt.update(grads, opt_state, params)
-                params = optim.apply_updates(params, updates)
+                with jax.named_scope(_GRAD):
+                    (loss, m), grads = jax.value_and_grad(
+                        loss_fn, has_aux=True
+                    )(params, mb)
+                    if cfg.distributed_axis:
+                        grads = jax.lax.pmean(grads, cfg.distributed_axis)
+                with jax.named_scope(_OPTIMIZER):
+                    updates, opt_state = opt.update(grads, opt_state, params)
+                    params = optim.apply_updates(params, updates)
                 return (params, opt_state), loss
 
             (params, opt_state), losses = jax.lax.scan(

@@ -1,6 +1,8 @@
 """repro.obs: sinks, streaming tap, run records, profiler hooks."""
 import csv
 import json
+import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +12,7 @@ import pytest
 from repro.bench.schema import check_provenance, check_run_record
 from repro.core.system import train_anakin
 from repro.envs import MatrixGame
+from repro.obs import profile
 from repro.obs import (
     ConsoleSink,
     CsvSink,
@@ -19,7 +22,6 @@ from repro.obs import (
     RetraceCounter,
     RunRecord,
     SeedAggregator,
-    measure_phase_timing,
     profile_trace,
     provenance,
     roofline_summary,
@@ -257,7 +259,8 @@ def test_retrace_counter_sees_fresh_compiles():
     assert rc.backend_compiles >= 1
     assert rc.compile_seconds > 0
     summary = rc.summary()
-    assert set(summary) == {"jaxpr_traces", "backend_compiles", "compile_seconds"}
+    assert set(summary) == {"jaxpr_traces", "backend_compiles", "compile_seconds",
+                            "cache_hits", "cache_misses"}
     # cached second call: no new compiles inside a fresh region
     fn = jax.jit(lambda x: x - 1.0)
     fn(jnp.ones((2,)))
@@ -267,10 +270,17 @@ def test_retrace_counter_sees_fresh_compiles():
 
 
 def test_profile_trace_writes_directory(tmp_path):
+    jax.jit(lambda x: x + 3.0)(jnp.ones((5,)))  # set-up before the window
     with profile_trace(tmp_path / "trace") as info:
         jax.block_until_ready(jnp.ones((8, 8)) @ jnp.ones((8, 8)))
     assert (tmp_path / "trace").is_dir()
     assert info["trace_dir"] == str(tmp_path / "trace")
+    # the set-up stages as the window started, kept for a reader afterwards
+    assert profile.last_trace() is info
+    at_start = info["stages_at_start"]
+    assert set(at_start) == {"trace_s", "lower_s", "compile_s", "compiles",
+                             "cache_hits", "cache_misses"}
+    assert at_start["compiles"] >= 1 and at_start["compile_s"] > 0
 
 
 def test_profile_trace_raises_when_profiler_cannot_start(tmp_path, monkeypatch):
@@ -300,10 +310,106 @@ def test_roofline_summary_counts_scanned_flops():
     assert summary["hlo_bytes"] > 0
 
 
-def test_measure_phase_timing_smoke():
-    phases = measure_phase_timing(
-        _vdn(), num_envs=2, key=jax.random.key(0), eval_episodes=2,
-        repeats=1,
-    )
-    assert set(phases) == {"rollout_seconds", "update_seconds", "eval_seconds"}
-    assert all(v > 0 for v in phases.values())
+def test_union_seconds_counts_nested_events_once():
+    assert profile.union_seconds([(0.0, 10.0), (2.0, 5.0), (9.0, 12.0), (20.0, 21.0)]) == 13.0
+    assert profile.union_seconds([(0.0, 10.0), (20.0, 21.0)], since=9.5) == 1.5
+    # an outer trace event ends after the inner one it holds: counted once
+    t0 = time.perf_counter()
+    time.sleep(0.3)
+    jax.monitoring.record_event_duration_secs(profile.TRACE_EVENT, 0.1)
+    jax.monitoring.record_event_duration_secs(profile.TRACE_EVENT, 0.2)
+    assert profile.stages(since=t0)["trace_s"] == pytest.approx(0.2, abs=0.02)
+
+
+def test_retrace_counter_counts_cache_retrieval():
+    with RetraceCounter() as rc:
+        time.sleep(0.05)
+        jax.monitoring.record_event(profile.CACHE_HIT_EVENT)
+        jax.monitoring.record_event_duration_secs(profile.CACHE_RETRIEVAL_EVENT, 0.04)
+    assert rc.compile_seconds == pytest.approx(0.04)
+    assert rc.summary()["cache_hits"] == 1
+    assert rc.summary()["cache_misses"] == 0
+
+
+HLO = """\
+%fused_computation.9 (param_0: s32[8]) -> f32[8] {
+  %param_0 = s32[8]{0} parameter(0)
+  %convert.10 = f32[8]{0} convert(%param_0), metadata={op_name="jit(run)/while/body/\
+closed_call/vmap(env_step)/vmap()/convert"}
+  ROOT %scatter.11 = f32[8]{0} scatter(%convert.10, %param_0, %convert.10), to_apply=%r.1
+}
+
+ENTRY %main.12 (a: f32[8], b: f32[8]) -> (f32[4]) {
+  %a = f32[8]{0} parameter(0)
+  %fusion.1 = f32[4,8]{1,0} fusion(%p.0), kind=kLoop, calls=%fused_computation.1, \
+metadata={op_name="jit(run)/while/body/closed_call/vmap(act)/dot_general" source_file="s.py" \
+source_line=3}, backend_config={"flag":{"a":"1"}}
+  %dot.2 = f32[4,8]{1,0} dot(%a, %b), metadata={op_name="jit(run)/while/body/update/cond/\
+branch_1_fun/vmap(update)/transpose(jvp(update))/dot_general"}
+  ROOT %tuple.3 = (f32[4]) tuple(%x), metadata={op_name="jit(run)/while/body/env_step/add"}
+  %dynamic-update-slice.4 = f32[8] dynamic-update-slice(%a, %b, %c), metadata={op_name=\
+"jit(run)/while/body/dynamic_update_slice"}
+  %add.5 = s32[] add(%i, %one), metadata={op_name="jit(run)/while/body/add"}
+  %copy.6 = f32[8] copy(%a)
+  %wrapped_add.7 = f32[] fusion(%a), kind=kLoop, calls=%c.7, metadata={op_name=\
+"jit(run)/while/body/observe/act/add"}
+  %mul.8 = f32[] multiply(%a, %b), metadata={op_name="jit(run)/while/body/vmap(observe)/mul"}
+  %fusion.13 = f32[8]{0:S(1)} fusion(%i), kind=kCustom, calls=%fused_computation.9
+  %copy-start.14 = (f32[4,8]{1,0}, f32[4,8]{1,0:S(1)}, u32[]) copy-start(%dot.2)
+  %copy-done.15 = f32[4,8]{1,0:S(1)} copy-done(%copy-start.14)
+  %copy.16 = f32[8]{0:S(1)} copy(%add.5)
+  %fusion.17 = f32[8]{0} fusion(%copy.16), kind=kLoop, calls=%fused_computation.1, \
+metadata={op_name="jit(run)/while/body/closed_call/vmap(act)/add"}
+}
+"""
+
+
+def test_op_phases_parses_scopes_from_hlo_text():
+    text = HLO.replace("\\\n", "")
+    assert profile.op_phases(text) == {
+        "convert.10": "env_step",
+        "fusion.1": "act",          # vmap(act), fusion line with backend_config
+        "dot.2": "update",          # update, transpose(jvp(update))
+        "tuple.3": "env_step",      # ROOT line
+        "mul.8": "observe",
+        "fusion.17": "act",
+        # no op_name: made by the compiler, so it takes the scope of what it fuses,
+        "scatter.11": "env_step",
+        "fusion.13": "env_step",
+        # else of its operand (an async copy of the update's dot)
+        "copy-start.14": "update",
+        "copy-done.15": "update",
+        # else of its user
+        "copy.16": "act",
+    }  # no phase (dynamic_update_slice, loop add, a copy of a parameter) or two: nothing
+    assert profile.scopes_in("a/vmap(observe)/act/transpose(jvp(update))") == (
+        "observe", "act", "update")
+    assert profile.scopes_in("x/mul;update/add") == ("update",)
+    assert profile.scopes_in("x/make_ppo_system.<locals>.update/jit(act_fn)/add") == ()
+    assert profile.op_phases(text, profile.UPDATE_PARTS) == {}
+
+
+SMALL_SYSTEMS = [("ippo", {}), ("rec_ippo", {"recurrent_core": "linear"}), ("vdn", {})]
+
+
+@pytest.mark.parametrize("name, overrides", SMALL_SYSTEMS, ids=[n for n, _ in SMALL_SYSTEMS])
+def test_every_phase_names_ops_of_the_compiled_program(name, overrides):
+    from repro.core.system import make_anakin
+    from repro.systems import make_system
+
+    kw = dict(rollout_len=4, hidden_sizes=(8,)) if name != "vdn" else {}
+    system = make_system(name, MatrixGame(horizon=10), **kw, **overrides)
+    program = make_anakin(system, 4, 2, num_seeds=2)
+    abstract = jax.eval_shape(program.init_fn, jax.random.key(0))
+    text = program.fused.lower(abstract).compile().as_text()
+    phases = profile.op_phases(text)
+    assert set(phases.values()) == set(profile.PHASES)
+    # no instruction sits under two phases
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    assert op_names and all(len(profile.scopes_in(n)) <= 1 for n in op_names)
+    if name != "vdn":
+        parts = profile.op_phases(text, profile.UPDATE_PARTS)
+        assert set(parts.values()) == set(profile.UPDATE_PARTS)
+        assert all(phases[op] == "update" for op in parts if op in phases)
+    # the runner registered the program: phase_map resolves the same map
+    assert profile.phase_map().items() >= phases.items()

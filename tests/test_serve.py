@@ -78,6 +78,20 @@ def test_queue_overflow_waits_for_free_slots():
     assert [r.uid for r in finished] == [0, 1, 2]
 
 
+def test_tick_log_splits_each_tick_into_its_parts():
+    _, system = _tiny("vdn")
+    train = system.init_train(jax.random.key(0))
+    engine = DecisionEngine(system, train, max_slots=2, warmup=False)
+    for i in range(3):
+        engine.submit(ServeRequest(uid=i, key=jax.random.key(i)))
+    engine.run_until_drained()
+    assert engine.tick_log
+    for tick in engine.tick_log:
+        parts = (tick["admit_s"], tick["decide_s"], tick["bookkeep_s"])
+        assert all(p >= 0 for p in parts)
+        assert sum(parts) == pytest.approx(tick["seconds"], rel=1e-6, abs=1e-9)
+
+
 def test_engine_rejects_bad_config():
     _, system = _tiny("vdn")
     train = system.init_train(jax.random.key(0))
