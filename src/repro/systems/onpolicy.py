@@ -39,10 +39,12 @@ Advantages are computed from *per-agent* rewards, so general-sum scenarios
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro import optim
 from repro.core.buffer import (
@@ -112,6 +114,92 @@ class PPOConfig:
     use_vtrace: bool = False
     vtrace_clip_rho: float = 1.0
     vtrace_clip_c: float = 1.0
+
+
+def _pack_rows(tree):
+    """Pack a pytree of ``(N, ...)`` leaves so one gather permutes its rows.
+
+    Each 4-byte leaf is flattened to ``width`` columns and bit-cast to
+    int32, and the columns are concatenated into one ``(N, F)`` array: on
+    the TPU a gather costs about the same per index whatever the row's
+    width, so one gather of packed rows replaces one gather per leaf.
+    Bit-casting keeps every bit (no float op ever sees an int's pattern),
+    so the unpacked rows equal the leaves' own rows bitwise.  A leaf of
+    another item size stays apart and is gathered on its own.
+
+    Returns ``(packed, others, unpack)``: ``unpack(rows, other_rows)``
+    rebuilds the tree from rows of ``packed`` and the matching rows of each
+    leaf of ``others``, under any common leading shape.
+    """
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    n = leaves[0].shape[0]
+    cols, others, slots, offset = [], [], [], 0
+    for x in leaves:
+        if x.dtype.itemsize != 4:
+            slots.append(len(others))
+            others.append(x)
+            continue
+        width = math.prod(x.shape[1:])
+        cols.append(jax.lax.bitcast_convert_type(x.reshape(n, width).T, jnp.int32))
+        slots.append((offset, width, x.shape[1:], x.dtype))
+        offset += width
+    features = jnp.concatenate(cols) if cols else jnp.zeros((0, n), jnp.int32)
+    # Build the columns feature-major and transpose once.  Left free, XLA
+    # gives the concatenation the gather's row-major layout, which makes
+    # each scalar leaf an (N, 1) column padded to a whole tile on the TPU.
+    features = with_layout_constraint(features, Layout(major_to_minor=(0, 1)))
+    packed = features.T
+
+    def unpack(rows, other_rows):
+        """The tree from packed rows ``(..., F)`` and the others' rows."""
+        lead = rows.shape[:-1]
+        # slice leaves out of the feature-major copy: whole rows, no padding
+        features = jnp.moveaxis(rows, -1, 0)
+        out = []
+        for slot in slots:
+            if isinstance(slot, int):
+                out.append(other_rows[slot])
+                continue
+            start, width, shape, dtype = slot
+            col = jnp.moveaxis(features[start:start + width], 0, -1)
+            out.append(jax.lax.bitcast_convert_type(col.reshape(lead + shape), dtype))
+        return treedef.unflatten(out)
+
+    return packed, others, unpack
+
+
+def _shuffled_minibatches(packed, others, unpack, key, num_minibatches):
+    """One epoch's minibatches: rows permuted by ``key``, cut to whole minibatches.
+
+    ``packed``, ``others`` and ``unpack`` come from `_pack_rows`.  The
+    leaves come out shaped ``(num_minibatches, N // num_minibatches, ...)``,
+    bitwise equal to gathering each leaf by the same permutation.
+    """
+    n = packed.shape[0]
+    mb_size = n // num_minibatches
+    perm = jax.random.permutation(key, n)[: mb_size * num_minibatches]
+    lead = (num_minibatches, mb_size)
+    rows = packed[perm].reshape(lead + packed.shape[1:])
+    other_rows = [x[perm].reshape(lead + x.shape[1:]) for x in others]
+    return unpack(rows, other_rows)
+
+
+def _update_rows(traj: Transition, adv, ret, centralised: bool):
+    """The rollout's ``(T*B, ...)`` rows that the feed-forward PPO epochs shuffle.
+
+    ``state`` is kept only for a centralised critic (MAPPO); IPPO's loss
+    never reads it, so it is None there and packs no columns.
+    """
+    T, B = traj.discount.shape
+    data = dict(
+        obs=traj.obs,
+        state=traj.state if centralised else None,
+        actions=traj.actions,
+        logp=traj.extras["logp"],
+        advantage=adv,
+        returns=ret,
+    )
+    return jax.tree_util.tree_map(lambda x: x.reshape((T * B,) + x.shape[2:]), data)
 
 
 def _make_gae(cfg: PPOConfig, ids):
@@ -320,32 +408,17 @@ def make_ppo_system(env, cfg: PPOConfig, centralised: bool, name: str) -> System
         traj: Transition = rollout_take(buffer)  # leaves (T, B, ...)
         with jax.named_scope(_ADVANTAGE):
             adv, ret = advantages(train, traj)
-        T, B = traj.discount.shape
-        data = dict(
-            obs=traj.obs,
-            state=traj.state,
-            actions=traj.actions,
-            logp=traj.extras["logp"],
-            advantage=adv,
-            returns=ret,
-        )
-        flat = jax.tree_util.tree_map(
-            lambda x: x.reshape((T * B,) + x.shape[2:]), data
-        )
+        with jax.named_scope(_MINIBATCH):
+            rows = _update_rows(traj, adv, ret, centralised)
+            packed, others, unpack = _pack_rows(rows)
 
         def epoch(carry, _):
             """One PPO epoch: shuffle, split into minibatches, scan `mb_step`."""
             params, opt_state, key = carry
             with jax.named_scope(_MINIBATCH):
                 key, kp = jax.random.split(key)
-                perm = jax.random.permutation(kp, T * B)
-                shuffled = jax.tree_util.tree_map(lambda x: x[perm], flat)
-                mb_size = (T * B) // cfg.num_minibatches
-                mbs = jax.tree_util.tree_map(
-                    lambda x: x[: mb_size * cfg.num_minibatches].reshape(
-                        (cfg.num_minibatches, mb_size) + x.shape[1:]
-                    ),
-                    shuffled,
+                mbs = _shuffled_minibatches(
+                    packed, others, unpack, kp, cfg.num_minibatches
                 )
 
             def mb_step(carry, mb):

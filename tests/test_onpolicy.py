@@ -1,13 +1,22 @@
 """IPPO/MAPPO behaviour tests (System-API ports of the flagship systems)."""
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.system import train_anakin
 from repro.envs import MatrixGame, SpeakerListener
-from repro.systems.onpolicy import PPOConfig, make_ippo, make_mappo
+from repro.systems.onpolicy import (
+    PPOConfig,
+    _pack_rows,
+    _shuffled_minibatches,
+    _update_rows,
+    make_ippo,
+    make_mappo,
+)
 
 # Learning-curve milestones recorded from the seed (pre-System) IPPO
 # implementation on matrix_game: PPOConfig(rollout_len=32, epochs=4,
@@ -165,3 +174,134 @@ def test_centralised_critic_sees_state():
     wm = jax.tree_util.tree_leaves(tm.params["critic"])[1]
     assert wi.shape[0] == spec.observations["agent_0"].shape[0]
     assert wm.shape[0] == spec.state.shape[0]
+
+
+# ----------------------------------------------- feed-forward minibatch shuffle
+
+_SHUFFLE_CFG = PPOConfig(rollout_len=6, epochs=3, num_minibatches=4)
+
+
+def _random_rollout(system, num_envs, key):
+    """A full rollout buffer of random rows; odd floats test bit-exactness."""
+    buf = system.init_buffer(num_envs)
+    leaves, treedef = jax.tree_util.tree_flatten(buf.storage)
+    keys = jax.random.split(key, len(leaves))
+    filled = []
+    for k, x in zip(keys, leaves):
+        if jnp.issubdtype(x.dtype, jnp.integer):
+            filled.append(jax.random.randint(k, x.shape, 0, 5, x.dtype))
+            continue
+        v = jax.random.normal(k, x.shape, x.dtype).ravel()
+        v = v.at[:4].set(jnp.array([-0.0, jnp.nan, jnp.inf, 1e-45], x.dtype))
+        filled.append(v.reshape(x.shape))
+    return buf._replace(storage=treedef.unflatten(filled), t=buf.t + _SHUFFLE_CFG.rollout_len)
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.int32) if x.dtype == np.float32 else x
+
+
+@pytest.mark.parametrize("make", [make_ippo, make_mappo], ids=["ippo", "mappo"])
+def test_packed_shuffle_matches_per_leaf_gather(make):
+    """One gather of the packed rows gives each leaf's own ``x[perm]`` minibatches, bit for bit."""
+    env = SpeakerListener()
+    system = make(env, _SHUFFLE_CFG)
+    num_envs = 5
+    traj = _random_rollout(system, num_envs, jax.random.key(3)).storage
+    ids = list(env.spec().agent_ids)
+    ka, kr = jax.random.split(jax.random.key(4))
+    adv = {a: jax.random.normal(jax.random.fold_in(ka, i), traj.discount.shape)
+           for i, a in enumerate(ids)}
+    ret = {a: jax.random.normal(jax.random.fold_in(kr, i), traj.discount.shape)
+           for i, a in enumerate(ids)}
+    centralised = make is make_mappo
+    rows = _update_rows(traj, adv, ret, centralised)
+
+    # the state rides along only for the centralised critic
+    n = _SHUFFLE_CFG.rollout_len * num_envs
+    if centralised:
+        np.testing.assert_array_equal(
+            _bits(rows["state"]), _bits(traj.state.reshape((n, -1)))
+        )
+    else:
+        assert rows["state"] is None
+    packed, others, unpack = _pack_rows(rows)
+    widths = sum(math.prod(x.shape[1:]) for x in jax.tree_util.tree_leaves(rows))
+    assert packed.shape == (n, widths) and packed.dtype == jnp.int32
+    assert others == []
+
+    key = jax.random.key(7)
+    got = _shuffled_minibatches(packed, others, unpack, key, _SHUFFLE_CFG.num_minibatches)
+    perm = jax.random.permutation(key, n)
+    mb = n // _SHUFFLE_CFG.num_minibatches
+    want = jax.tree_util.tree_map(
+        lambda x: x[perm][: mb * _SHUFFLE_CFG.num_minibatches].reshape(
+            (_SHUFFLE_CFG.num_minibatches, mb) + x.shape[1:]
+        ),
+        rows,
+    )
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for v in value if isinstance(value, (tuple, list)) else (value,):
+            if hasattr(v, "jaxpr") and hasattr(v, "consts"):
+                yield v.jaxpr
+            elif hasattr(v, "eqns"):
+                yield v
+
+
+def _gathers_outside(jaxpr, skip_scan_length):
+    """Gather eqns of ``jaxpr`` and its sub-jaxprs, not entering scans of that length."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and eqn.params["length"] == skip_scan_length:
+            continue
+        if eqn.primitive.name == "gather":
+            found.append(eqn)
+        for sub in _sub_jaxprs(eqn):
+            found += _gathers_outside(sub, skip_scan_length)
+    return found
+
+
+def _scans(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _scans(sub)
+
+
+@pytest.mark.parametrize("make", [make_ippo, make_mappo], ids=["ippo", "mappo"])
+def test_ppo_epoch_gathers_rollout_rows_once(make):
+    """Each epoch permutes the rollout with one gather of the packed rows, not one per leaf."""
+    env = SpeakerListener()
+    cfg = _SHUFFLE_CFG
+    system = make(env, cfg)
+    num_envs = 5
+    train = system.init_train(jax.random.key(0))
+    buf = _random_rollout(system, num_envs, jax.random.key(1))
+    jaxpr = jax.make_jaxpr(system.update)(train, buf, jax.random.key(2)).jaxpr
+    epochs = [e for e in _scans(jaxpr) if e.params["length"] == cfg.epochs]
+    assert len(epochs) == 1
+    body = epochs[0].params["jaxpr"].jaxpr
+    assert any(e.params["length"] == cfg.num_minibatches for e in _scans(body))
+    n = cfg.rollout_len * num_envs
+    row_gathers = [
+        e for e in _gathers_outside(body, cfg.num_minibatches)
+        if e.invars[0].aval.shape[:1] == (n,)
+    ]
+    assert len(row_gathers) == 1, [e.invars[0].aval for e in row_gathers]
+
+    spec = env.spec()
+    ids = list(spec.agent_ids)
+    # obs per agent, then actions, logp, advantage and returns per agent
+    width = sum(spec.observations[a].shape[0] for a in ids) + 4 * len(ids)
+    if make is make_mappo:
+        width += spec.state.shape[0]
+    assert row_gathers[0].invars[0].aval.shape == (n, width)
