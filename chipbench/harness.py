@@ -9,6 +9,8 @@ file of its own under ``chipbench/``, found by the name the entry gives:
   runners/<runner>.py       one module per runner kind, ``run(cell) -> dict``
   metrics/<metric>.py       one reader per per-layer metric, ``read(ctx)``
   limits/<cell>.json        the limit of each number the check compares
+  reference/<module>.py     a training family's check and FLOP count, named by the
+                            configuration's "reference" key (``reference(config)``)
 
 Nothing here imports JAX at module level, so the tests can load cells
 without a backend.
@@ -122,6 +124,27 @@ def reader(metric: str):
     return load_module(BENCH_DIR / "metrics" / f"{metric}.py")
 
 
+def reference(config):
+    """The reference module a training configuration names: ``reference/<config["reference"]>.py``.
+
+    It gives ``NUMBERS``, the names its cells' limits files may use;
+    ``numbers(sweep, control=False, detail=None)``, the compared numbers of
+    a runner's ``Sweep`` after set-up, worst over its checked lanes; and
+    ``train_flops_per_env_step(config)``, the matmul FLOPs ``mfu.train``
+    divides by.  A configuration without the key, or naming no such file,
+    is an error.
+    """
+    name = config.get("reference")
+    if name is None:
+        raise KeyError(f"configuration {config.get('name')!r} names no reference module: "
+                       f"give it \"reference\": \"<module>\" for chipbench/reference/<module>.py")
+    path = BENCH_DIR / "reference" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"configuration {config.get('name')!r} names the reference "
+                                f"module {name!r}, but {path} does not exist")
+    return load_module(path)
+
+
 def peaks(device_kind: str) -> Dict[str, float]:
     """The published peaks of ``device_kind``; an unknown device is an error."""
     table = _load_json(BENCH_DIR / "peaks.json")["devices"]
@@ -178,8 +201,8 @@ def build_system(config):
     from repro.systems import make_system
 
     env = make_env(config["env"], **config["env_kwargs"])
-    overrides = dict(config["system_overrides"])
-    overrides["hidden_sizes"] = tuple(overrides["hidden_sizes"])
+    overrides = {k: tuple(v) if isinstance(v, list) else v
+                 for k, v in config["system_overrides"].items()}
     return make_system(config["system"], env, **overrides)
 
 

@@ -24,7 +24,6 @@ import numpy as np
 
 import devtrace
 import harness
-from reference import ppo, train_check
 from repro.core.system import make_anakin
 from repro.obs.profile import RetraceCounter, profile_trace
 
@@ -98,23 +97,13 @@ class Sweep:
         self.st = None
 
     def check(self, control=False, detail=None):
-        """The compared numbers, worst over the checked lanes (per-leaf gaps into ``detail``)."""
-        spec = ppo.Spec.from_config(self.cell.config)
-        ids = list(self.system.spec.agent_ids)
-        env_p = dict(self.cell.config["env_kwargs"])
-        worst = {}
-        for i, lane in enumerate(self.lanes):
-            rows = train_check.lane_rows(self.snaps[1:], i, ids)
-            produced = {
-                "params": [jax.tree_util.tree_map(lambda x: x[i], s["params"]) for s in self.snaps],
-                "mu1": jax.tree_util.tree_map(lambda x: x[i], self.snaps[1]["mu1"]),
-            }
-            nums = train_check.lane_numbers(spec, env_p, self.key, self.S, int(lane), rows,
-                                            produced, self.T, control=control,
-                                            detail=detail)
-            for k, v in nums.items():
-                worst[k] = max(worst.get(k, 0.0), v)
-        return worst
+        """The compared numbers, worst over the checked lanes, read by the configuration's module.
+
+        ``harness.reference`` finds the module.  With ``control`` its
+        reference in bfloat16 takes the program's place; ``detail`` (a
+        list) receives per-leaf gaps.
+        """
+        return harness.reference(self.cell.config).numbers(self, control, detail)
 
 
 def run(cell, *, seed, seconds, trace, devices, clock, peaks):

@@ -22,6 +22,32 @@ SMALL_SERVE = dict(slots=8, rate_per_s=60.0, checked_requests=1000, trace_second
                    drain_seconds=3)
 
 
+def assert_cell_loads(harness, name):
+    """The rules every cell of ``harness``'s tree keeps: its files load by name and agree.
+
+    A training cell's limits are numbers its configuration's reference
+    module gives; a serving cell's are the serving check's.
+    """
+    cell = harness.load_cell(name)
+    entry = next(w for w in harness.load_benchmark()["workloads"] if w["name"] == name)
+    assert (harness.BENCH_DIR / "traffic" / f"{entry['traffic']}.json").exists()
+    assert harness.runner(cell.traffic["runner"]).run
+    e2e = {m["name"] for m in cell.end_to_end}
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert cell.per_layer, "every cell reports a per-layer metric"
+    for m in cell.per_layer:
+        assert m["moves"] in e2e
+        assert harness.reader(m["name"]).read
+    assert cell.limits and all(v > 0 for v in cell.limits.values())
+    if cell.traffic["runner"] == "anakin_seeds":
+        known = harness.reference(cell.config).NUMBERS
+    else:
+        from reference import serve_check
+
+        known = serve_check.NUMBERS
+    assert set(cell.limits) <= set(known)
+
+
 def small_cell(name):
     """The cell ``name`` at a size a CPU test holds: every width kept, fewer envs and steps."""
     cell = harness.load_cell(name)

@@ -7,13 +7,18 @@ import sys
 
 import pytest
 
-from chipbench_testing import BENCH, harness
+from chipbench_testing import BENCH, assert_cell_loads, harness
 
 ROOT = BENCH.parent
 BENCH_JSON = json.loads((ROOT / "BENCHMARK.json").read_text())
 WITH_PENDING = harness.load_benchmark()
 # admitted cells and the pending ones (chipbench/pending.json) alike
 CELLS = [w["name"] for w in WITH_PENDING["workloads"]]
+TRAINING_CONFIGS = sorted({
+    w["config"] for w in WITH_PENDING["workloads"]
+    if json.loads((BENCH / "traffic" / f"{w['traffic']}.json").read_text())["runner"]
+    == "anakin_seeds"
+})
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
@@ -34,21 +39,24 @@ def test_top_level_keys_and_names():
 
 @pytest.mark.parametrize("name", CELLS)
 def test_every_cell_loads_by_name(name):
-    cell = harness.load_cell(name)
-    entry = next(w for w in WITH_PENDING["workloads"] if w["name"] == name)
-    assert (BENCH / "traffic" / f"{entry['traffic']}.json").exists()
-    assert harness.runner(cell.traffic["runner"]).run
-    e2e = {m["name"] for m in cell.end_to_end}
-    assert "setup_s" in e2e and len(e2e) >= 2
-    assert cell.per_layer, "every cell reports a per-layer metric"
-    for m in cell.per_layer:
-        assert m["moves"] in e2e
-        assert harness.reader(m["name"]).read
-    assert cell.limits and all(v > 0 for v in cell.limits.values())
-    from reference import serve_check, train_check
+    assert_cell_loads(harness, name)
 
-    known = train_check.NUMBERS if cell.traffic["runner"] == "anakin_seeds" else serve_check.NUMBERS
-    assert set(cell.limits) <= set(known)
+
+@pytest.mark.parametrize("name", TRAINING_CONFIGS)
+def test_every_training_config_names_its_reference(name):
+    entry = next(c for c in WITH_PENDING["configs"] if c["name"] == name)
+    module = harness.reference(json.loads((ROOT / entry["file"]).read_text()))
+    assert module.NUMBERS and all(NAME.match(n) for n in module.NUMBERS)
+    assert callable(module.numbers) and callable(module.train_flops_per_env_step)
+
+
+def test_a_missing_reference_is_a_clear_error():
+    config = json.loads((BENCH / "configs" / "ippo_smax.json").read_text())
+    with pytest.raises(FileNotFoundError, match="no_such_check"):
+        harness.reference(dict(config, reference="no_such_check"))
+    del config["reference"]
+    with pytest.raises(KeyError, match="names no reference module"):
+        harness.reference(config)
 
 
 def test_every_config_is_used_and_stands_alone():
